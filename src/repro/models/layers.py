@@ -348,64 +348,74 @@ def attention_block(
     def la(nm):
         return (p.get(f"{nm}_lora_a"), p.get(f"{nm}_lora_b")) if use_lora else (None, None)
 
-    q = _proj_lora(x, p["wq"], *la("wq"))
-    k = _proj_lora(x, p["wk"], *la("wk"))
-    v = _proj_lora(x, p["wv"], *la("wv"))
-    if cfg.qk_norm:
-        q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
-        k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
-    if positions.ndim == 1:
-        positions = jnp.broadcast_to(positions[None, :], (b, s))
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
-    q = constrain(q, "heads")
-    k = constrain(k, "kv")
-    v = constrain(v, "kv")
+    with jax.named_scope("attn_qkv"):
+        q = _proj_lora(x, p["wq"], *la("wq"))
+        k = _proj_lora(x, p["wk"], *la("wk"))
+        v = _proj_lora(x, p["wv"], *la("wv"))
+        if cfg.qk_norm:
+            q = rmsnorm(q, p["q_norm"], cfg.norm_eps)
+            k = rmsnorm(k, p["k_norm"], cfg.norm_eps)
+        if positions.ndim == 1:
+            positions = jnp.broadcast_to(positions[None, :], (b, s))
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
+        q = constrain(q, "heads")
+        k = constrain(k, "kv")
+        v = constrain(v, "kv")
 
     new_cache = None
     if cache is None:
-        if causal and getattr(constrain, "attn_impl", "chunked") == "tri":
-            o = chunked_attention_tri(q, k, v, window=window)
-        else:
-            o = chunked_attention(q, k, v, causal=causal, window=window)
+        with jax.named_scope("attn_core"):
+            if causal and getattr(constrain, "attn_impl", "chunked") == "tri":
+                o = chunked_attention_tri(q, k, v, window=window)
+            else:
+                o = chunked_attention(q, k, v, causal=causal, window=window)
     else:
         kc, vc, ln = cache["k"], cache["v"], cache["len"]
         if s == 1:
             # single-token decode: insert then attend (SWA uses a ring buffer)
             S = kc.shape[1]
-            if window is not None and S <= window:
-                slot = ln % S
-            else:
-                slot = jnp.minimum(ln, S - 1)
+            with jax.named_scope("kv_write"):
+                if window is not None and S <= window:
+                    slot = ln % S
+                else:
+                    slot = jnp.minimum(ln, S - 1)
             kv_len = jnp.minimum(ln + 1, S)
             sp = getattr(constrain, "sp_decode", None)
             if sp is not None:
-                o, kc, vc = sp(q, k, v, kc, vc, slot, kv_len)
+                # the sequence-parallel kernel inserts and attends in one call
+                with jax.named_scope("attn_core"):
+                    o, kc, vc = sp(q, k, v, kc, vc, slot, kv_len)
             else:
-                idx = slot[:, None]
-                bidx = jnp.arange(b)[:, None]
-                kc = kc.at[bidx, idx].set(k)
-                vc = vc.at[bidx, idx].set(v)
-                o = decode_attention(q, kc, vc, kv_len)
+                with jax.named_scope("kv_write"):
+                    idx = slot[:, None]
+                    bidx = jnp.arange(b)[:, None]
+                    kc = kc.at[bidx, idx].set(k)
+                    vc = vc.at[bidx, idx].set(v)
+                with jax.named_scope("attn_core"):
+                    o = decode_attention(q, kc, vc, kv_len)
             new_cache = {"k": kc, "v": vc, "len": ln + 1}
         else:
             # prefill: write cache (ring-rotated when SWA window < prompt) and
             # run chunked attention over the full prompt
             S = kc.shape[1]
-            if s > S:  # SWA: keep only the last S keys, at t % S slots
-                idx = np.arange(s - S, s) % S
-                kc = kc.at[:, idx].set(k[:, -S:])
-                vc = vc.at[:, idx].set(v[:, -S:])
-            else:
-                kc = jax.lax.dynamic_update_slice_in_dim(kc, k, 0, axis=1)
-                vc = jax.lax.dynamic_update_slice_in_dim(vc, v, 0, axis=1)
-            o = chunked_attention(q, k, v, causal=causal, window=window)
+            with jax.named_scope("kv_write"):
+                if s > S:  # SWA: keep only the last S keys, at t % S slots
+                    idx = np.arange(s - S, s) % S
+                    kc = kc.at[:, idx].set(k[:, -S:])
+                    vc = vc.at[:, idx].set(v[:, -S:])
+                else:
+                    kc = jax.lax.dynamic_update_slice_in_dim(kc, k, 0, axis=1)
+                    vc = jax.lax.dynamic_update_slice_in_dim(vc, v, 0, axis=1)
+            with jax.named_scope("attn_core"):
+                o = chunked_attention(q, k, v, causal=causal, window=window)
             new_cache = {"k": kc, "v": vc, "len": cache["len"] + s}
-    o = constrain(o, "heads")
-    out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    lao, lbo = la("wo")
-    if lao is not None:
-        out = out + (o.reshape(b, s, -1) @ lao) @ lbo
+    with jax.named_scope("attn_out"):
+        o = constrain(o, "heads")
+        out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        lao, lbo = la("wo")
+        if lao is not None:
+            out = out + (o.reshape(b, s, -1) @ lao) @ lbo
     return out, new_cache
 
 

@@ -2,6 +2,12 @@
 
 Layers are stacked on a leading axis and consumed with ``jax.lax.scan`` so the
 lowered HLO is depth-independent (critical for 94-layer dry-run compiles).
+
+Each layer boundary runs under a ``jax.named_scope`` from one fixed vocabulary:
+``embed``, ``layers`` (the layer scan), ``norm``, ``attn_qkv``, ``kv_write``,
+``attn_core``, ``attn_out``, ``mlp``, ``head``. The scopes reach only the
+compiled HLO's metadata (each op's ``op_name`` path), which a profiler trace of
+the step is split by; the program computes the same with or without them.
 """
 from __future__ import annotations
 
@@ -62,36 +68,42 @@ def init_dense(cfg, key):
 
 def _embed_inputs(cfg, params, batch, constrain):
     """Token (+ frontend stub) embedding. Returns (x [b,s,d], positions [b,s])."""
-    tok = batch["tokens"]
-    x = jnp.take(params["embed"], tok, axis=0)
-    if cfg.frontend:
-        fe = batch["frontend"].astype(x.dtype) @ params["frontend_proj"]
-        x = jnp.concatenate([fe, x], axis=1)
-    b, s, _ = x.shape
-    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
-    return constrain(x, "hidden"), positions
+    with jax.named_scope("embed"):
+        tok = batch["tokens"]
+        x = jnp.take(params["embed"], tok, axis=0)
+        if cfg.frontend:
+            fe = batch["frontend"].astype(x.dtype) @ params["frontend_proj"]
+            x = jnp.concatenate([fe, x], axis=1)
+        b, s, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+        return constrain(x, "hidden"), positions
 
 
 def _logits(cfg, params, x):
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
+    with jax.named_scope("head"):
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        return jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
 
 
 def _layer_body(cfg, constrain, x, lp, lcache, positions, window):
     """Returns (out, aux_loss, new_cache)."""
+    with jax.named_scope("norm"):
+        xn = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
     a, new_cache = L.attention_block(
-        lp["attn"], L.rmsnorm(x, lp["ln1"], cfg.norm_eps), cfg,
+        lp["attn"], xn, cfg,
         positions=positions, causal=True, window=window,
         cache=lcache, constrain=constrain,
     )
     h = x + a
-    hn = L.rmsnorm(h, lp["ln2"], cfg.norm_eps)
-    if cfg.moe is not None:
-        from repro.models.moe import moe_block
+    with jax.named_scope("norm"):
+        hn = L.rmsnorm(h, lp["ln2"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if cfg.moe is not None:
+            from repro.models.moe import moe_block
 
-        m, aux = moe_block(lp["mlp"], hn, cfg.moe, constrain)
-    else:
-        m, aux = L.mlp_block(lp["mlp"], hn, constrain), jnp.float32(0.0)
+            m, aux = moe_block(lp["mlp"], hn, cfg.moe, constrain)
+        else:
+            m, aux = L.mlp_block(lp["mlp"], hn, constrain), jnp.float32(0.0)
     out = h + m
     return constrain(out, "hidden"), aux, new_cache
 
@@ -110,10 +122,11 @@ def dense_forward(
         x, positions = _embed_inputs(cfg, params, batch, constrain)
     else:
         # decode: single new token at position cache["len"]
-        tok = batch["tokens"]  # [b, 1]
-        x = jnp.take(params["embed"], tok, axis=0)
-        positions = cache["len"][:, None] + jnp.zeros_like(tok)
-        x = constrain(x, "hidden")
+        with jax.named_scope("embed"):
+            tok = batch["tokens"]  # [b, 1]
+            x = jnp.take(params["embed"], tok, axis=0)
+            positions = cache["len"][:, None] + jnp.zeros_like(tok)
+            x = constrain(x, "hidden")
 
     def body(carry, xs):
         x, aux = carry
@@ -130,16 +143,19 @@ def dense_forward(
 
     aux0 = jnp.float32(0.0)
     if cache is None:
-        (x, aux), _ = jax.lax.scan(
-            lambda c, lp: body(c, (lp, None)), (x, aux0), params["blocks"]
-        )
+        with jax.named_scope("layers"):
+            (x, aux), _ = jax.lax.scan(
+                lambda c, lp: body(c, (lp, None)), (x, aux0), params["blocks"]
+            )
         new_cache = None
     else:
         lcaches = {"k": cache["k"], "v": cache["v"],
                    "len": jnp.broadcast_to(cache["len"], (cfg.n_layers, *cache["len"].shape))}
-        (x, aux), new_lc = jax.lax.scan(body, (x, aux0), (params["blocks"], lcaches))
+        with jax.named_scope("layers"):
+            (x, aux), new_lc = jax.lax.scan(body, (x, aux0), (params["blocks"], lcaches))
         new_cache = {"k": new_lc["k"], "v": new_lc["v"], "len": cache["len"] + 1}
-    x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
     return x, aux / cfg.n_layers, new_cache
 
 
@@ -208,9 +224,11 @@ def dense_prefill(cfg, params, batch, cache, constrain=lambda a, k: a):
 
     lcaches = {"k": cache["k"], "v": cache["v"],
                "len": jnp.broadcast_to(cache["len"], (cfg.n_layers, *cache["len"].shape))}
-    (x, _), new_lc = jax.lax.scan(body, (x, jnp.float32(0.0)), (params["blocks"], lcaches))
-    x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    logits = _logits(cfg, params, x[:, -1:])
+    with jax.named_scope("layers"):
+        (x, _), new_lc = jax.lax.scan(body, (x, jnp.float32(0.0)), (params["blocks"], lcaches))
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["ln_f"], cfg.norm_eps)
+        logits = _logits(cfg, params, x[:, -1:])
     return logits, {"k": new_lc["k"], "v": new_lc["v"], "len": cache["len"] + s}
 
 
