@@ -10,8 +10,11 @@ The window runs whole steps until ``--seconds`` have passed; rates are all
 the work of the window over its length, tails are over all its steps, and a
 compilation inside it fails the run. With ``--trace 1`` the profiler records
 the first ``trace_steps`` steps of the window, and the per-layer metrics are
-read from that trace. After the window the device's peak memory is read, the
-program's state is freed and the reference checks the run's outputs.
+read from that trace; after the window and the reading of the device's peak
+memory, the step is compiled afresh for the map of its operations to the
+program's layer scopes (``bench/scopes.py``), which splits the trace by
+scope. Then the program's state is freed and the reference checks the run's
+outputs.
 
 The last line on standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1`` also
@@ -31,7 +34,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import peaks, spec, trace as tr
+from bench import peaks, scopes, spec, trace as tr
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 
@@ -112,7 +115,7 @@ class CompileCounter:
 
 
 def make_loop(cell: spec.Cell, mesh, seed: int):
-    return cell.loop(cell.model, cell.mix, mesh, seed)
+    return cell.loop(cell.family, cell.model, cell.mix, mesh, seed)
 
 
 def run_window(loop, seconds: float, counter: CompileCounter, trace_steps=0,
@@ -151,9 +154,7 @@ def run_window(loop, seconds: float, counter: CompileCounter, trace_steps=0,
 
 
 def summarize(planes) -> tr.Summary:
-    spans = tr.host_spans(planes)
-    steps = [s for s in spans if s[0] == "bench.step"]
-    return tr.reduce(tr.device_ops(planes), spans, (steps[0][1], steps[-1][2]),
+    return tr.reduce(tr.device_ops(planes), tr.host_spans(planes), tr.step_window(planes),
                      tr.device_ops(planes, line_re=tr.ASYNC_LINE))
 
 
@@ -192,6 +193,7 @@ def run(args, t_start: float, root: Path = spec.ROOT, allow_cpu: bool = False) -
             window_s=window_s, steps=len(step_s), items=items, step_s=step_s)
     if planes is not None:
         r.trace = summarize(planes)
+        r.trace.scope_s = scopes.split(loop, planes, cell.family.SCOPES)
         r.traced_steps = min(cell.mix["trace_steps"], len(step_s))
         r.traced_work = loop.work(first_step, first_step + r.traced_steps)
         device.update(busy_s=r.trace.busy_s, window_s=r.trace.window_s)

@@ -10,11 +10,15 @@ same first tokens and served tokens through the same drawn cache:
 
 * ``logit_gap``: the widest gap by which a served token's reference logit
   lies below the reference's best logit at that position;
-* ``kv_err``: the worst, over layers and over K and V, relative L2 error of
-  the cache entries the program wrote for the served positions.
+* ``kv_err``: the worst, over layers and over the cache's entries (the
+  family's ``written``: K and V for qwen3), relative L2 error of the
+  entries the program wrote for the served positions.
 
 The control (``fp8``) reads, at the same positions, the gap of the token the
-fp8 reference puts first, and the error of its keys and values.
+fp8 reference puts first, and the error of its cache entries.
+
+Everything that depends on the model comes from the cell's family
+(``bench/families/<model_type>.py``).
 """
 from __future__ import annotations
 
@@ -25,20 +29,19 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from bench import counts, peaks, program, seeded
-from bench.reference.model import Reference
+from bench import peaks, program, seeded
 
 
-def refuse(model, mix: dict, chips: int, seconds: float) -> str | None:
+def refuse(family, model, mix: dict, chips: int, seconds: float) -> str | None:
     """Why the mix's cache cannot hold a window of ``seconds``, or None. The
     cache has to hold the warm-up and every step the window could take at
-    the least time a step can take: its FLOPs and bytes (bench/counts.py)
-    at the peaks of the fastest chip ``bench/peaks.py`` knows."""
+    the least time a step can take: its FLOPs and bytes (the family's
+    counts) at the peaks of the fastest chip ``bench/peaks.py`` knows."""
     b, fill = mix["batch"], mix["fill"]
     flops = max(p.flops for p in peaks.PEAKS.values()) * chips
     bw = max(p.hbm_bw for p in peaks.PEAKS.values()) * chips
-    least = max(counts.decode_flops(model, b, fill) / flops,
-                counts.decode_bytes(model, b, fill) / bw)
+    least = max(family.decode_flops(model, b, fill) / flops,
+                family.decode_bytes(model, b, fill) / bw)
     need = mix["warmup_steps"] + math.ceil(seconds / least) + 1
     room = mix["context"] - 1 - fill
     if need > room:
@@ -49,18 +52,19 @@ def refuse(model, mix: dict, chips: int, seconds: float) -> str | None:
 
 
 class Decode:
-    def __init__(self, model, mix: dict, mesh, seed: int):
-        self.m, self.mix, self.mesh, self.seed = model, mix, mesh, seed
+    def __init__(self, family, model, mix: dict, mesh, seed: int):
+        self.family, self.m, self.mix, self.mesh, self.seed = family, model, mix, mesh, seed
         self.batch, self.context, self.fill = mix["batch"], mix["context"], mix["fill"]
         self.steps = 0
 
     def setup(self):
-        m = self.m
+        fam, m = self.family, self.m
         self.fn, (p_sds, b_sds, c_sds) = program.build(
-            m, self.mix["name"], "decode", self.context, self.batch, self.mesh)
+            fam.arch_config(m), self.mix["name"], "decode", self.context,
+            self.batch, self.mesh)
         self.tok_sharding = b_sds["tokens"].sharding
-        self.params = program.make_params(m, p_sds, self.seed)
-        self.cache = program.make_cache(m, c_sds, self.seed, self.fill)
+        self.params = fam.make_params(m, p_sds, self.seed)
+        self.cache = fam.make_cache(m, c_sds, self.seed, self.fill)
         self.argmax = jax.jit(lambda lg: jnp.argmax(lg[:, -1], -1).astype(jnp.int32)[:, None],
                               out_shardings=self.tok_sharding)
         self.first = seeded.first_tokens(self.seed, m, self.batch)
@@ -88,15 +92,13 @@ class Decode:
     def work(self, first: int, last: int) -> tuple:
         """``(flops, bytes)`` that steps ``first`` to ``last - 1`` need."""
         ctx = [self.fill + i for i in range(first, last)]
-        return (sum(counts.decode_flops(self.m, self.batch, c) for c in ctx),
-                sum(counts.decode_bytes(self.m, self.batch, c) for c in ctx))
+        fam = self.family
+        return (sum(fam.decode_flops(self.m, self.batch, c) for c in ctx),
+                sum(fam.decode_bytes(self.m, self.batch, c) for c in ctx))
 
     def finish(self) -> dict:
         """The run's outputs; frees the program's state."""
-        n = self.steps
-        lo = self.fill
-        kv = jax.device_get({"k": self.cache["k"][:, :, lo:lo + n],
-                             "v": self.cache["v"][:, :, lo:lo + n]})
+        kv = jax.device_get(self.family.written(self.cache, self.fill, self.steps))
         out = {"served": np.concatenate(self.served, 1), "kv": kv}
         del self.params, self.cache
         return out
@@ -111,17 +113,17 @@ class Decode:
         # comes after every served position and, being causal, changes none
         inputs = np.concatenate([self.first, served[:, :-1]], 1)
         inputs = np.pad(inputs, ((0, 0), (0, -n % 64)))
-        ref = Reference(m, self.seed, precisions)
+        ref = self.family.Reference(m, self.seed, precisions)
         kv_err = {p: 0.0 for p in precisions}
         prog_kv = out["kv"]
 
         def on_layer(i, kv):
-            want = [x[:, :n] for x in kv["f32"]]
+            want = {k: x[:, :n] for k, x in kv["f32"].items()}
             for p in precisions:
-                got = ((prog_kv["k"][i], prog_kv["v"][i]) if p == "f32"
-                       else [x[:, :n] for x in kv[p]])
-                for g, r in zip(got, want):
-                    kv_err[p] = max(kv_err[p], rel_err(g, r))
+                got = ({k: prog_kv[k][i] for k in want} if p == "f32"
+                       else {k: x[:, :n] for k, x in kv[p].items()})
+                for k, r in want.items():
+                    kv_err[p] = max(kv_err[p], rel_err(got[k], r))
 
         hid = ref.run(inputs, self.fill,
                       past=lambda i: ref.cache(i, self.batch, self.context, self.fill),

@@ -11,10 +11,14 @@ through the reference:
   over the sampled prompts: the largest absolute difference over the
   vocabulary, relative to the reference's largest logit (one wrong logit
   shows as much as a wrong vector);
-* ``kv_err``: the worst, over layers and over K and V, relative L2 error of
-  the cache the program wrote for the last prompt (every position).
+* ``kv_err``: the worst, over layers and over the cache's entries (the
+  family's ``written``: K and V for qwen3), relative L2 error of the cache
+  the program wrote for the last prompt (every position).
 
 The control (``fp8``) reads the same two numbers for the fp8 reference.
+
+Everything that depends on the model comes from the cell's family
+(``bench/families/<model_type>.py``).
 """
 from __future__ import annotations
 
@@ -23,9 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from bench import counts, program, seeded
+from bench import program, seeded
 from bench.kinds.decode import rel_err
-from bench.reference.model import Reference
 
 
 def max_err(got, want) -> float:
@@ -36,18 +39,19 @@ def max_err(got, want) -> float:
 
 
 class Prefill:
-    def __init__(self, model, mix: dict, mesh, seed: int):
-        self.m, self.mix, self.mesh, self.seed = model, mix, mesh, seed
+    def __init__(self, family, model, mix: dict, mesh, seed: int):
+        self.family, self.m, self.mix, self.mesh, self.seed = family, model, mix, mesh, seed
         self.batch, self.length = mix["batch"], mix["length"]
         self.steps = 0
 
     def setup(self):
-        m = self.m
+        fam, m = self.family, self.m
         self.fn, (p_sds, b_sds, c_sds) = program.build(
-            m, self.mix["name"], "prefill", self.length, self.batch, self.mesh)
+            fam.arch_config(m), self.mix["name"], "prefill", self.length,
+            self.batch, self.mesh)
         self.tok_sharding = b_sds["tokens"].sharding
         self.len_sharding = c_sds["len"].sharding
-        self.params = program.make_params(m, p_sds, self.seed)
+        self.params = fam.make_params(m, p_sds, self.seed)
         self.cache = program.empty_cache(c_sds)
         self.logits = []
         for _ in range(self.mix["warmup_steps"]):
@@ -69,7 +73,7 @@ class Prefill:
 
     def work(self, first: int, last: int) -> tuple:
         n = last - first
-        return n * counts.prefill_flops(self.m, self.batch, self.length), None
+        return n * self.family.prefill_flops(self.m, self.batch, self.length), None
 
     def finish(self) -> dict:
         rng = np.random.default_rng([self.seed, self.steps])
@@ -78,12 +82,12 @@ class Prefill:
                        if k > 1 else {self.steps - 1})
         out = {"picks": picks,
                "logits": {i: self.logits[i] for i in picks},
-               "kv": {"k": self.cache["k"], "v": self.cache["v"]}}
+               "kv": self.family.written(self.cache, 0, self.length)}
         del self.params, self.cache, self.logits
         return out
 
     def check(self, out: dict, precisions=("f32",)) -> dict:
-        ref = Reference(self.m, self.seed, precisions)
+        ref = self.family.Reference(self.m, self.seed, precisions)
         worst = {p: {"logits_err": 0.0, "kv_err": 0.0} for p in precisions}
         last = out["picks"][-1]
         for i in out["picks"]:
@@ -94,10 +98,10 @@ class Prefill:
                     return
                 want = kv["f32"]
                 for p in precisions:
-                    got = ((out["kv"]["k"][li], out["kv"]["v"][li]) if p == "f32"
+                    got = ({k: out["kv"][k][li] for k in want} if p == "f32"
                            else kv[p])
-                    for g, r in zip(got, want):
-                        worst[p]["kv_err"] = max(worst[p]["kv_err"], rel_err(g, r))
+                    for k, r in want.items():
+                        worst[p]["kv_err"] = max(worst[p]["kv_err"], rel_err(got[k], r))
 
             hid = ref.run(toks, 0, on_layer=on_layer)
             lg = ref.logits({p: h[:, -1:] for p, h in hid.items()})

@@ -6,7 +6,9 @@ instruction in its metadata, ``op_name="jit(decode_step)/layers/while/body/
 closed_call/attn_core/dot_general"``; a fusion keeps its root's, and one whose
 root has none (a multi-output fusion's tuple) takes the scope its fused
 instructions share. So each leaf device operation of the step's module is
-mapped to the innermost scope of the vocabulary on its path:
+mapped to the innermost scope on its path of the vocabulary of the cell's
+family (``SCOPES`` of ``bench/families/<model_type>.py``); a scope that one
+family's program adds counts under itself:
 
 * an operation under ``layers`` and under no scope inside it counts under
   ``layers``: the scan's slicing of its inputs and stacking of its outputs;
@@ -21,12 +23,14 @@ Leaf operations are those :func:`bench.trace.reduce` counts per operation
 (a loop is not counted beside its body), clipped to the window; seconds are
 means over the devices traced. The TPU's trace names each operation but
 carries no ``op_name``, so the map comes from the step's optimized HLO
-(:func:`step_hlo`), compiled after the window.
+(:func:`step_hlo`), compiled after the window (:func:`split`, which
+``bench/harness.py`` calls in a traced run; its readers are
+:data:`METRICS`).
 
     PYTHONPATH=src python3 -m bench.scopes --workload <name> --seed <n> --seconds <s>
 
 runs one cell as ``bench/run.py --trace 1`` does, up to the trace, and prints
-one JSON line: the device time per traced step in each scope, the readers of
+one JSON line: the device time per traced step in every scope, the readers of
 ``bench/metrics/`` that read it, the traced and untraced steps' rates, and
 the seconds the split takes after the window (``split_s``: the compile and
 the map) beside those of the harness's own reduction (``summarize_s``).
@@ -39,8 +43,6 @@ from collections import defaultdict
 
 from bench import trace as tr
 
-SCOPES = ("embed", "layers", "norm", "attn_qkv", "kv_write", "attn_core",
-          "attn_out", "mlp", "head")
 UNSCOPED = "unscoped"
 MODULES_LINE = r"^XLA Modules$"
 METRICS = ("layer_carry_ms.decode", "unscoped_ms.decode", "attention_ms.decode",
@@ -54,20 +56,21 @@ _CALLS = re.compile(r"calls=%([^\s,]+)")
 _OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 
 
-def scope_of(op_name: str | None) -> str:
-    """The innermost scope of the vocabulary on the path ``op_name``, else
-    :data:`UNSCOPED`."""
+def scope_of(op_name: str | None, vocab) -> str:
+    """The innermost scope of the vocabulary ``vocab`` on the path
+    ``op_name``, else :data:`UNSCOPED`."""
     for seg in reversed((op_name or "").split("/")):
-        if seg in SCOPES:
+        if seg in vocab:
             return seg
     return UNSCOPED
 
 
-def hlo_scopes(hlo: str) -> tuple[str, dict]:
+def hlo_scopes(hlo: str, vocab) -> tuple[str, dict]:
     """``(module name, {instruction: scope})`` of an optimized HLO text, every
-    computation's instructions included. A fusion whose root carries no
-    ``op_name`` (a multi-output fusion's tuple) counts under the one scope
-    its fused instructions share, else as unscoped."""
+    computation's instructions included, by the scopes of ``vocab``. A
+    fusion whose root carries no ``op_name`` (a multi-output fusion's tuple)
+    counts under the one scope its fused instructions share, else as
+    unscoped."""
     lines = hlo.splitlines()
     m = _MODULE.match(lines[0]) if lines else None
     if not m:
@@ -82,7 +85,7 @@ def hlo_scopes(hlo: str) -> tuple[str, dict]:
         if not ins:
             continue
         name, op = ins.group(1), _OP_NAME.search(line)
-        named[name] = scope_of(op.group(1)) if op else None
+        named[name] = scope_of(op.group(1), vocab) if op else None
         members[comp].append(name)
         called = _CALLS.search(line)
         if called:
@@ -129,11 +132,20 @@ def by_scope(ops_by_device: dict, scopes: dict, window) -> dict:
     return dict(out)
 
 
+def split(loop, planes, vocab) -> dict:
+    """Seconds of the traced window's leaf operations of the step loop's
+    step in each scope of ``vocab`` (:func:`by_scope`), by the map of a
+    fresh compile of the step (:func:`step_hlo`): after the window, before
+    the loop frees its state."""
+    module, mapping = hlo_scopes(step_hlo(loop), vocab)
+    return by_scope(module_ops(planes, module), mapping, tr.step_window(planes))
+
+
 def ms_per_step(run, kind: str, scope: str):
     """A reader's value: ms per traced step of ``kind`` in ``scope``, or None
     where there is no trace, no scope map, no operation of the step carries
     a scope (a program without scopes) or the scope holds no time."""
-    scope_s = getattr(run.trace, "scope_s", None)
+    scope_s = run.trace.scope_s if run.trace is not None else None
     if run.kind != kind or not scope_s or not run.traced_steps:
         return None
     if not any(v > 0 for k, v in scope_s.items() if k != UNSCOPED):
@@ -200,10 +212,7 @@ def main(argv=None) -> int:
         t0 = time.perf_counter()
         summary = harness.summarize(planes)
         t1 = time.perf_counter()
-        module, scopes = hlo_scopes(step_hlo(loop))
-        steps = [s for s in tr.host_spans(planes) if s[0] == "bench.step"]
-        summary.scope_s = by_scope(module_ops(planes, module), scopes,
-                                   (steps[0][1], steps[-1][2]))
+        summary.scope_s = split(loop, planes, cell.family.SCOPES)
         split_s, summarize_s = time.perf_counter() - t1, t1 - t0
     n = min(k, len(step_s))
     run = harness.Run(kind=cell.mix["kind"], chips=mesh.size,
@@ -218,12 +227,12 @@ def main(argv=None) -> int:
             metrics[name] = value
     per_step = 1e3 / n
     print(json.dumps({
-        "workload": args.workload, "seed": args.seed, "module": module,
+        "workload": args.workload, "seed": args.seed,
         "device": {"platform": dev.platform, "kind": dev.device_kind,
                    "count": mesh.size},
         "metrics": metrics,
         "scope_ms": {s: summary.scope_s.get(s, 0.0) * per_step
-                     for s in (*SCOPES, UNSCOPED)},
+                     for s in (*cell.family.SCOPES, UNSCOPED)},
         "scope_sum_ms": sum(summary.scope_s.values()) * per_step,
         "leaf_ms": sum(summary.op_s.values()) * per_step,
         "busy_ms": summary.busy_s * per_step,

@@ -1,14 +1,16 @@
-"""Everything a run makes from ``--seed``: weights, decode caches, tokens.
+"""Everything a run makes from ``--seed``: the streams that weights, decode
+caches and tokens are drawn from, and the draws every family shares.
 
-The weights are generated one layer at a time by :func:`layer_weights`, in
-a layout of the benchmark's own (the published model's tensors, named as
-below), so that the program's stacked parameters and the reference's
-layer-by-layer weights are the same draws: the program's copy is ``lax.map``
-of the same function over the layers, made on the device in one jitted call;
-the reference calls it for one layer at a time. Values are rounded to the
-served dtype (bfloat16) and the reference upcasts them. On the CPU the two
-copies are equal bit for bit; on a TPU two compilations can round a few
-values to neighbouring bfloat16 numbers (PERF.md, Open questions).
+A family (``bench/families/<model_type>.py``) draws its weights one layer at
+a time, in a layout of the benchmark's own, from the key
+:func:`layer_key` gives, so that the program's stacked parameters and the
+reference's layer-by-layer weights are the same draws: the program's copy is
+``lax.map`` of the same function over the layers, made on the device in one
+jitted call; the reference calls it for one layer at a time. Values are
+rounded to the served dtype (bfloat16) and the reference upcasts them. On
+the CPU the two copies are equal bit for bit; on a TPU two compilations can
+round a few values to neighbouring bfloat16 numbers (PERF.md, Open
+questions).
 
 Streams are separated by ``fold_in`` of a fixed purpose number, so adding a
 stream never changes another one. Jitted generators take the stream's key as
@@ -21,10 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 
 WEIGHTS, CACHE, TOKENS = 0, 1, 2
-
-#: RMS of the drawn cache contents: qk-normed keys have unit RMS; values are
-#: a unit-RMS input times ``initializer_range`` 0.02 over 1,024 inputs
-K_RMS, V_RMS = 1.0, 0.64
 
 
 def root_key(seed: int):
@@ -47,14 +45,6 @@ def stream(seed: int, purpose: int):
     return jax.random.fold_in(root_key(seed), purpose)
 
 
-def layer_shapes(m) -> dict:
-    d, h, kh, dh, ff = m.d, m.heads, m.kv_heads, m.head_dim, m.ff
-    return {"ln1": (d,), "wq": (d, h, dh), "wk": (d, kh, dh),
-            "wv": (d, kh, dh), "q_norm": (dh,), "k_norm": (dh,),
-            "wo": (h, dh, d), "ln2": (d,), "w_gate": (d, ff),
-            "w_up": (d, ff), "w_down": (ff, d)}
-
-
 def uniform(key, shape, rms: float):
     """Uniform draws of RMS ``rms`` on a grid of 2**16 points, made with
     integer operations and one float multiply."""
@@ -63,45 +53,17 @@ def uniform(key, shape, rms: float):
     return u.astype(jnp.float32) * (rms * 3**0.5 / 32768)
 
 
-def _draw(key, name: str, shape, m, dtype):
-    if len(shape) == 1:  # a norm weight: 1 + q / 512, q uniform on [-128, 128)
-        bits = jax.random.bits(key, shape, jnp.uint32)
-        q = (bits >> 24).astype(jnp.int32) - 128
-        return (1.0 + q.astype(jnp.float32) * 2.0**-9).astype(dtype)
-    return uniform(key, shape, m.init_std).astype(dtype)
-
-
-def layer_weights(key, m, dtype=jnp.bfloat16) -> dict:
-    """The weights of the layer whose key is ``key``."""
-    return {name: _draw(jax.random.fold_in(key, i), name, shape, m, dtype)
-            for i, (name, shape) in enumerate(sorted(layer_shapes(m).items()))}
+def norm_weight(key, shape, dtype):
+    """An RMSNorm weight: 1 + q / 512, q uniform on [-128, 128)."""
+    bits = jax.random.bits(key, shape, jnp.uint32)
+    q = (bits >> 24).astype(jnp.int32) - 128
+    return (1.0 + q.astype(jnp.float32) * 2.0**-9).astype(dtype)
 
 
 def layer_key(wkey, i):
     """The key of layer ``i`` (an int or a traced index) under the weights
     key ``wkey`` (``stream(seed, WEIGHTS)``)."""
     return jax.random.fold_in(jax.random.fold_in(wkey, 3), i)
-
-
-def outer_weights(key, m, dtype=jnp.bfloat16) -> dict:
-    """Embedding, final norm and (untied) output head under the weights key."""
-    w = {"embed": _draw(jax.random.fold_in(key, 0), "embed", (m.vocab, m.d), m, dtype),
-         "ln_f": _draw(jax.random.fold_in(key, 2), "ln_f", (m.d,), m, dtype)}
-    if not m.tied:
-        w["lm_head"] = _draw(jax.random.fold_in(key, 1), "lm_head",
-                             (m.d, m.vocab), m, dtype)
-    return w
-
-
-def cache_layer(key, m, batch: int, length: int, fill: int, dtype=jnp.bfloat16):
-    """``(k, v)`` of one layer's cache, ``[batch, length, kv_heads,
-    head_dim]``: drawn for positions below ``fill``, zero above."""
-    shape = (batch, length, m.kv_heads, m.head_dim)
-    kk, kv = jax.random.split(key)
-    live = (jnp.arange(length) < fill)[None, :, None, None]
-    k = jnp.where(live, uniform(kk, shape, K_RMS), 0.0)
-    v = jnp.where(live, uniform(kv, shape, V_RMS), 0.0)
-    return k.astype(dtype), v.astype(dtype)
 
 
 def cache_key(ckey, i):

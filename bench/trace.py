@@ -20,7 +20,9 @@ written with ``jax.profiler.TraceAnnotation``) on the same clock. From those:
   ``while``, spans its body's collectives too and hides none of them;
 * idle gaps by host activity: each stretch of the window in which the
   device is idle, split by the innermost (shortest) host span covering it,
-  summed by span name; idle time under no span is ``(no span)``.
+  summed by span name; idle time under no span is ``(no span)``;
+* per-scope device time (``scope_s``): the step's leaf operations by the
+  program's layer scopes, set by the harness from ``bench/scopes.py``.
 
 Device numbers are means over the devices traced.
 """
@@ -100,6 +102,13 @@ def host_spans(planes, prefix="bench.") -> list:
                   key=lambda e: e[1])
 
 
+def step_window(planes) -> tuple:
+    """``(start_s, end_s)`` from the first traced step's start to the last
+    one's end (the harness's ``bench.step`` spans)."""
+    steps = [s for s in host_spans(planes) if s[0] == "bench.step"]
+    return steps[0][1], steps[-1][2]
+
+
 def union(intervals) -> list:
     """Disjoint, sorted ``[(start, end)]`` covering ``intervals``."""
     out = []
@@ -161,6 +170,7 @@ class Summary:
     collective_s: float = 0.0
     collective_exposed_s: float = 0.0
     idle_by_span: dict = field(default_factory=dict)
+    scope_s: dict = field(default_factory=dict)  # {scope: seconds}, bench/scopes.py
 
     @property
     def idle_s(self) -> float:

@@ -39,14 +39,16 @@ MIXES = {
 
 def write_root(root: Path, config=TINY, mixes=MIXES, limits=None) -> Path:
     """A checkout root with ``tiny.decode`` and ``tiny.prefill`` cells, the
-    benchmark's step loops, metric readers and its limits (or ``limits``)."""
+    benchmark's step loops, families, metric readers and its limits (or
+    ``limits``)."""
     real = json.loads((REPO / "BENCHMARK.json").read_text())
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     (root / "bench" / "limits").mkdir()
     shutil.copytree(REPO / "bench" / "metrics", root / "bench" / "metrics")
-    shutil.copytree(REPO / "bench" / "kinds", root / "bench" / "kinds",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for d in ("kinds", "families"):
+        shutil.copytree(REPO / "bench" / d, root / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(config))
     cells = []
     for mix, body in mixes.items():

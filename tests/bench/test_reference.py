@@ -16,16 +16,18 @@ import numpy as np
 import pytest
 
 from bench_fixtures import TINY  # noqa: F401 (puts the checkout on sys.path)
-from bench import config, program, seeded
+from bench import program, seeded
+from bench.families import qwen3
 from bench.kinds.decode import rel_err
-from bench.reference.model import Reference, fp8
+from bench.families.qwen3 import Reference
+from bench.reference.ops import fp8
 
 TOL = 2e-4
 
 
 @pytest.fixture(scope="module")
 def model():
-    return config.from_dict("tiny", dict(TINY, torch_dtype="float32",
+    return qwen3.from_dict("tiny", dict(TINY, torch_dtype="float32",
                                          tie_word_embeddings=False))
 
 
@@ -38,8 +40,9 @@ def mesh():
 
 def test_prefill_matches_reference(model, mesh):
     seed, length = 5, 48
-    fn, (p_sds, _, c_sds) = program.build(model, "t", "prefill", length, 2, mesh)
-    params = program.make_params(model, p_sds, seed)
+    fn, (p_sds, _, c_sds) = program.build(qwen3.arch_config(model), "t", "prefill",
+                                          length, 2, mesh)
+    params = qwen3.make_params(model, p_sds, seed)
     toks = seeded.prompt(seed, 0, model, 2, length)
     logits, cache = fn(params, {"tokens": jnp.asarray(toks)}, program.empty_cache(c_sds))
     kv = {}
@@ -48,15 +51,16 @@ def test_prefill_matches_reference(model, mesh):
     want = ref.logits({"f32": hid["f32"][:, -1:]})["f32"]
     assert rel_err(logits, want) < TOL
     for i in range(model.layers):
-        assert rel_err(cache["k"][i], kv[i][0]) < TOL
-        assert rel_err(cache["v"][i], kv[i][1]) < TOL
+        assert rel_err(cache["k"][i], kv[i]["k"]) < TOL
+        assert rel_err(cache["v"][i], kv[i]["v"]) < TOL
 
 
 def test_decode_through_drawn_cache_matches_reference(model, mesh):
     seed, context, fill, steps = 9, 64, 40, 5
-    fn, (p_sds, _, c_sds) = program.build(model, "t", "decode", context, 2, mesh)
-    params = program.make_params(model, p_sds, seed)
-    cache = program.make_cache(model, c_sds, seed, fill)
+    fn, (p_sds, _, c_sds) = program.build(qwen3.arch_config(model), "t", "decode",
+                                          context, 2, mesh)
+    params = qwen3.make_params(model, p_sds, seed)
+    cache = qwen3.make_cache(model, c_sds, seed, fill)
     tok = seeded.first_tokens(seed, model, 2)
     inputs, outs = [], []
     for _ in range(steps):
@@ -72,8 +76,8 @@ def test_decode_through_drawn_cache_matches_reference(model, mesh):
     want = ref.logits(hid)["f32"]
     assert rel_err(jnp.concatenate(outs, 1), want) < TOL
     for i in range(model.layers):
-        assert rel_err(cache["k"][i, :, fill:fill + steps], kv[i][0]) < TOL
-        assert rel_err(cache["v"][i, :, fill:fill + steps], kv[i][1]) < TOL
+        assert rel_err(cache["k"][i, :, fill:fill + steps], kv[i]["k"]) < TOL
+        assert rel_err(cache["v"][i, :, fill:fill + steps], kv[i]["v"]) < TOL
     assert int(cache["len"][0]) == fill + steps
 
 
@@ -83,9 +87,9 @@ def test_stacked_weights_equal_layer_weights(model):
     m = dataclasses.replace(model, dtype="bfloat16")
     wkey = seeded.stream(2**33 + 1, seeded.WEIGHTS)
     stacked = jax.jit(lambda k: jax.lax.map(
-        lambda i: seeded.layer_weights(seeded.layer_key(k, i), m), jnp.arange(m.layers)))(wkey)
+        lambda i: qwen3.layer_weights(seeded.layer_key(k, i), m), jnp.arange(m.layers)))(wkey)
     for i in range(m.layers):
-        one = seeded.layer_weights(seeded.layer_key(wkey, i), m)
+        one = qwen3.layer_weights(seeded.layer_key(wkey, i), m)
         for name, x in one.items():
             assert np.array_equal(np.asarray(stacked[name][i]), np.asarray(x)), name
 

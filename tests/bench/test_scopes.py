@@ -3,6 +3,7 @@
 operations and traces; and the map of the tiny cells' real steps, compiled on
 the CPU."""
 import gzip
+import time
 from pathlib import Path
 
 import pytest
@@ -10,6 +11,7 @@ import pytest
 import bench_fixtures  # puts the checkout on sys.path
 from bench_fixtures import tiny_root  # noqa: F401 (a fixture)
 from bench import harness, scopes, spec
+from bench.families import qwen3
 from bench import trace as tr
 
 HLO = """HloModule jit_decode_step, is_scheduled=true, entry_computation_layout={(f32[8]{0})->f32[8]{0}}
@@ -60,11 +62,11 @@ DATA = Path(__file__).parent / "data"
     (None, scopes.UNSCOPED),
 ])
 def test_innermost_scope_wins(op_name, scope):
-    assert scopes.scope_of(op_name) == scope
+    assert scopes.scope_of(op_name, qwen3.SCOPES) == scope
 
 
 def test_hlo_scopes_maps_every_instruction():
-    module, m = scopes.hlo_scopes(HLO)
+    module, m = scopes.hlo_scopes(HLO, qwen3.SCOPES)
     assert module == "jit_decode_step"
     assert m["fusion.1"] == "mlp"  # a fusion counts under its root's scope
     assert m["multiply.3"] == "mlp"
@@ -76,7 +78,21 @@ def test_hlo_scopes_maps_every_instruction():
     assert m["sub_fusion.12"] == "attn_qkv"
     assert m["add_fusion.13"] == scopes.UNSCOPED
     with pytest.raises(ValueError):
-        scopes.hlo_scopes("not HLO")
+        scopes.hlo_scopes("not HLO", qwen3.SCOPES)
+
+
+@pytest.mark.parametrize("vocab,scope", [
+    (qwen3.SCOPES, "mlp"), ((*qwen3.SCOPES, "expert"), "expert")])
+def test_a_familys_own_scope_counts_under_itself(vocab, scope):
+    """A scope that one family's program adds (here ``expert``, inside
+    ``mlp``) counts under itself in that family's vocabulary, and under the
+    scope around it in qwen3's."""
+    hlo = HLO.replace("closed_call/mlp/mul", "closed_call/mlp/expert/mul")
+    _, m = scopes.hlo_scopes(hlo, vocab)
+    assert m["fusion.1"] == m["multiply.3"] == scope
+    assert m["dynamic-slice.4"] == "layers"
+    assert scopes.scope_of("jit(s)/layers/while/body/closed_call/mlp/expert/dot",
+                           vocab) == scope
 
 
 @pytest.mark.parametrize("step,expect", [
@@ -92,7 +108,7 @@ def test_hlo_scopes_of_the_chips_compiled_steps(step, expect):
     """The optimized HLO of qwen3-0.6b's steps as the TPU v5e's compiler gave
     it; the operations are the longest of each cell's traced step."""
     with gzip.open(DATA / f"tpu_v5e.qwen3-0.6b.{step}.hlo.gz", "rt") as f:
-        module, m = scopes.hlo_scopes(f.read())
+        module, m = scopes.hlo_scopes(f.read(), qwen3.SCOPES)
     assert module == f"jit_{step.split('_')[0]}_step"
     assert {k: m[k] for k in expect} == expect
 
@@ -125,7 +141,7 @@ def test_module_ops_leave_out_other_modules():
 
 
 def test_buckets_sum_to_the_steps_leaf_time():
-    _, m = scopes.hlo_scopes(HLO)
+    _, m = scopes.hlo_scopes(HLO, qwen3.SCOPES)
     ops = scopes.module_ops(_planes(), "jit_decode_step")
     ops["/device:TPU:1"] = [("fusion.1", 0.0, 4.0), ("copy.9", 4.0, 6.0)]
     window = (0.0, 13.5)
@@ -177,6 +193,77 @@ def test_readers_return_none_without_scopes(name, case):
     assert _read(name, run) is None
 
 
+SPLIT = {  # scope: (operation, seconds in the traced window)
+    "decode": {"attn_core": ("fusion.1", 0.1), "kv_write": ("fusion.2", 0.02),
+               "mlp": ("fusion.3", 0.08), "layers": ("dynamic-slice.4", 0.4),
+               "unscoped": ("copy.5", 0.2)},
+    "prefill": {"attn_core": ("fusion.1", 0.5), "mlp": ("fusion.3", 0.3)},
+}
+READS = {"layer_carry_ms": "layers", "unscoped_ms": "unscoped",
+         "attention_ms": "attn_core", "kv_write_ms": "kv_write", "mlp_ms": "mlp"}
+
+
+def _step_hlo(kind):
+    lines = [f"HloModule jit_{kind}_step, is_scheduled=true", "",
+             "ENTRY %main.1 (p: f32[8]) -> f32[8] {", "  %p = f32[8]{0} parameter(0)"]
+    for scope, (op, _) in SPLIT[kind].items():
+        path = {"layers": "layers/while/body/dynamic_slice", "unscoped": None}.get(
+            scope, f"layers/while/body/closed_call/{scope}/x")
+        meta = f', metadata={{op_name="jit({kind}_step)/{path}"}}' if path else ""
+        lines.append(f"  %{op} = f32[8]{{0}} fusion(%p), kind=kLoop{meta}")
+    return "\n".join(lines + ["}"])
+
+
+def _step_trace(kind):
+    """One device running the step's module over [0, 1] s, its operations
+    one after another, and two traced steps on the host."""
+    ops, t = [], 0.0
+    for op, secs in SPLIT[kind].values():
+        ops.append((f"%{op} = f32[8] fusion(...)", t, t + secs))
+        t += secs
+    return [("/device:TPU:0", [("XLA Modules", [(f"jit_{kind}_step(3)", 0.0, 1.0)]),
+                               ("XLA Ops", ops)]),
+            ("/host:CPU", [("python", [("bench.step", 0.0, 0.5),
+                                       ("bench.step", 0.5, 1.0)])])]
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_the_split_reaches_the_readers_on_the_result_line(kind, tiny_root, monkeypatch):
+    """A traced run splits the trace by the map of the step compiled after
+    the window and after the peak memory is read, while the loop still holds
+    its state; every scope metric of the cell is on the result line, in ms
+    per traced step. The trace is made by hand (a CPU trace has no TPU)."""
+    seen = []
+    real_window, real_peak = harness.run_window, harness.memory_peak
+
+    def run_window(loop, seconds, counter, trace_steps=0, trace_dir=None):
+        window_s, items, step_s, _ = real_window(loop, seconds, counter)
+        return window_s, items, step_s, _step_trace(kind)
+
+    def memory_peak(mesh):
+        seen.append("memory_peak")
+        return real_peak(mesh)
+
+    def step_hlo(loop):
+        seen.append("step_hlo" if hasattr(loop, "params") else "step_hlo after finish")
+        return _step_hlo(kind)
+
+    monkeypatch.setattr(harness, "run_window", run_window)
+    monkeypatch.setattr(harness, "memory_peak", memory_peak)
+    monkeypatch.setattr(scopes, "step_hlo", step_hlo)
+    args = harness.parse(["--workload", f"tiny.{kind}", "--seed", "5", "--seconds", "0.5",
+                          "--trace", "1"])
+    result = harness.run(args, time.perf_counter(), root=tiny_root, allow_cpu=True)
+    assert seen == ["memory_peak", "step_hlo"]
+    assert result["correct"], result["checks"]
+    steps = spec.load_cell(f"tiny.{kind}", tiny_root).mix["trace_steps"]
+    want = {f"{reader}.{kind}": 1e3 * SPLIT[kind][scope][1] / steps
+            for reader, scope in READS.items() if scope in SPLIT[kind]}
+    assert len(want) == {"decode": 5, "prefill": 2}[kind]
+    got = {k: v["value"] for k, v in result["metrics"].items() if k in scopes.METRICS}
+    assert got == pytest.approx(want)
+
+
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
 def test_the_tiny_steps_map_to_every_scope(kind, tiny_root):
     """The map of the step the loop ran, fetched from its live state as a
@@ -186,10 +273,10 @@ def test_the_tiny_steps_map_to_every_scope(kind, tiny_root):
     loop = harness.make_loop(cell, mesh, 2200001404)
     with mesh:
         loop.setup()
-        module, m = scopes.hlo_scopes(scopes.step_hlo(loop))
+        module, m = scopes.hlo_scopes(scopes.step_hlo(loop), qwen3.SCOPES)
     assert module == f"jit_{kind}_step"
     # a prompt fills the prefill cache whole and XLA removes its write
-    want = set(scopes.SCOPES) - ({"kv_write"} if kind == "prefill" else set())
+    want = set(qwen3.SCOPES) - ({"kv_write"} if kind == "prefill" else set())
     assert want <= set(m.values()), sorted(want - set(m.values()))
     assert scopes.UNSCOPED in m.values()
 
@@ -224,8 +311,9 @@ def test_the_map_is_compiled_past_a_cached_step_without_scopes(tiny_root, tmp_pa
                                           sharding=loop.tok_sharding)
             cached = loop.fn.lower(loop.params, {"tokens": tokens}, loop.cache).compile()
             fresh = scopes.step_hlo(loop)
-        assert set(scopes.hlo_scopes(cached.as_text())[1].values()) == {scopes.UNSCOPED}
-        assert "attn_core" in scopes.hlo_scopes(fresh)[1].values()
+        cached_map = scopes.hlo_scopes(cached.as_text(), qwen3.SCOPES)[1]
+        assert set(cached_map.values()) == {scopes.UNSCOPED}
+        assert "attn_core" in scopes.hlo_scopes(fresh, qwen3.SCOPES)[1].values()
     finally:
         for k, v in was.items():
             jax.config.update(k, v)
